@@ -1,8 +1,9 @@
 """Benchmark driver — one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV. Distributed benchmarks run in
-subprocesses with forced host devices; everything else runs on the single
-real device. ``--full`` widens the sweeps.
+subprocesses on forced host CPU devices (``JAX_PLATFORMS=cpu``; their rows
+carry ``device=cpu``); everything else runs on the single real device.
+``--full`` widens the sweeps.
 
 ``--json`` additionally writes the perf-trajectory artifacts (repo root):
 ``BENCH_spgemm.json`` from the spgemm_local rows, ``BENCH_dist.json``
@@ -81,7 +82,10 @@ def run_dist(which: str, devices: int | None = None):
     """
     if devices is None:
         devices = int(os.environ.get("REPRO_DEVICES", "16"))
-    env = dict(os.environ, REPRO_DEVICES=str(devices), REPRO_OBS="1")
+    # the children run on forced host devices: pin them to the CPU so they
+    # never contend for an accelerator this process may hold
+    env = dict(os.environ, REPRO_DEVICES=str(devices), REPRO_OBS="1",
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     trace = os.environ.get("REPRO_TRACE")
     if trace:
@@ -103,11 +107,12 @@ def run_dist(which: str, devices: int | None = None):
             except ValueError:
                 summary = None
             continue
-        print(line)
         if not line or line.startswith("#"):
+            print(line)
             continue
         name, us, derived = line.split(",", 2)
-        rows.append((name, float(us), derived))
+        rows.append((name, float(us), f"{derived};device=cpu"))
+        print(f"{name},{us},{rows[-1][2]}")
     return rows, summary
 
 
@@ -150,6 +155,8 @@ def main() -> None:
     ap.add_argument("--json", action="store_true",
                     help="write BENCH_spgemm.json (spgemm rows + speedups)")
     args, _ = ap.parse_known_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
     if args.json and only is not None and not {"spgemm_local", "dist",

@@ -15,6 +15,7 @@ import numpy as np
 from repro.apps import hipmcl
 from repro.core import DistSpMat, make_grid
 from repro.io import read_generalized_tuples
+from repro.launch.cache import use_compile_cache
 
 
 def planted_network(k=6, size=12, p_in=0.7, p_out=0.01, seed=0):
@@ -31,6 +32,7 @@ def planted_network(k=6, size=12, p_in=0.7, p_out=0.01, seed=0):
 
 
 def main():
+    use_compile_cache()
     n, edges = planted_network()
     truth = np.arange(n) // 12
     with tempfile.TemporaryDirectory() as td:

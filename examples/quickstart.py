@@ -13,9 +13,11 @@ from repro.core import (ARITHMETIC, MIN_PLUS, DistSpMat, make_grid,
                         make_semiring, spgemm_2d)
 from repro.apps import bfs_levels, fastsv, pagerank, triangle_count
 from repro.io import rmat_coo
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     mesh = make_grid(1, 1)           # same code runs on any (pr, pc) grid
     shape, rows, cols, vals = rmat_coo(9, 8, seed=0, symmetrize=True,
                                        drop_self_loops=True)

@@ -27,9 +27,11 @@ from repro.models.config import ModelConfig
 from repro.train import (AdamWConfig, SyntheticLM, init_opt_state,
                          latest_step, make_train_step, restore_checkpoint,
                          save_checkpoint)
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="assigned arch id (smoke config); default: custom "

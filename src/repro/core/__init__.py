@@ -14,7 +14,7 @@ Layering:
   spmm          1.5D + true-2D SpMM
   assign        skew-aware vector assign / extract (§3.3)
   plan          capacity planner + variant rules of thumb (§5, §7)
-  compat        jax version shims (single home for post-0.4.x APIs)
+  compat        mesh axis types + the engine's shard_map (jax 0.9.0)
 """
 from . import compat, merge, semiring
 from .coo import COO, SENTINEL, column_range, ewise_intersect, ewise_union

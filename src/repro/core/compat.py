@@ -1,72 +1,34 @@
-"""JAX version-compat shims (single home for every post-0.4.x API we touch).
+"""The two jax mesh/shard_map entry points the engine builds on.
 
-The repo targets the jax that ships in the container (0.4.37 today) while
-staying forward-compatible with newer releases. Three surfaces moved between
-0.4.x and 0.5+/0.6+ and are guarded here with ``getattr`` fallbacks:
+The repo targets the installed jax (0.9.0) only. Everything else in core/
+imports these names from here, so the engine's shard_map semantics are
+chosen in one place:
 
-  - ``jax.sharding.AxisType`` (and ``jax.make_mesh(axis_types=...)``):
-    explicit-vs-auto axis types only exist on newer jax. On 0.4.x every mesh
-    axis is implicitly "auto", so the kwarg is simply dropped.
-  - ``jax.shard_map``: the public binding is new; 0.4.x has
-    ``jax.experimental.shard_map.shard_map``. The experimental version also
-    takes ``check_rep`` (replication checking) which we disable — our bodies
-    use collectives whose replication typing predates the checker's rules.
-  - ``jax.lax.pcast``: newer shard_map requires constants entering a scan
-    carry to be cast to "varying"; on 0.4.x the concept does not exist and
-    the identity is the correct behavior.
-
-Everything else in core/ imports these names from here, never from jax
-directly, so a jax upgrade is a one-file audit.
+  - ``make_mesh``: every axis is ``AxisType.Auto`` (the SUMMA/3D programs
+    place data with explicit shard_map specs, not sharding-in-types).
+  - ``shard_map``: varying-manual-axes checking (``check_vma``) is off.
+    The engine's bodies were written and validated under the unchecked
+    semantics: windowed ``lax.cond`` skip branches return invariant
+    constants, and scan carries start invariant and become varying after
+    the first collective. Both are correct per-device programs that the
+    checker's typing rules refuse. The mapped function is jitted: called
+    eagerly, jax 0.9 runs a shard_map body one primitive at a time and
+    compiles each primitive anew on every call.
 """
 from __future__ import annotations
 
 import jax
 
-AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with auto axis types when the installed jax has them."""
-    kwargs = {}
-    if AXIS_TYPE is not None:
-        kwargs["axis_types"] = (AXIS_TYPE.Auto,) * len(tuple(axis_shapes))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         devices=devices, **kwargs)
+    """``jax.make_mesh`` with every axis typed ``Auto``."""
+    axis_shapes = tuple(axis_shapes)
+    return jax.make_mesh(axis_shapes, tuple(axis_names), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_shapes))
 
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map_experimental(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, check_rep=False)
-
-
-def pvary(x, axes):
-    """Cast a replicated value to "varying" over ``axes``.
-
-    Modern jax spells it ``jax.lax.pvary``; some intermediate versions had
-    ``jax.lax.pcast(..., to="varying")``; 0.4.x has neither and needs
-    nothing (shard_map did not track varying-ness yet) — identity.
-    """
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is not None:
-        return fn(x, axes)
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axes, to="varying")
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params across the TPUCompilerParams rename.
-
-    Newer jax: ``pltpu.CompilerParams``; 0.4.x: ``pltpu.TPUCompilerParams``.
-    Imported lazily so core/ never pays the pallas import cost.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with ``check_vma=False``, jitted."""
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))

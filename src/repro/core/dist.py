@@ -51,11 +51,7 @@ def _ceil(a, b):
 
 def make_grid(pr: int, pc: int, layers: int = 1,
               devices=None) -> Mesh:
-    """Process grid for sparse ops: ('row','col') or ('layer','row','col').
-
-    Axis types (auto) are requested only on jax versions that have them —
-    see core/compat.py for the 0.4.x fallback.
-    """
+    """Process grid for sparse ops: ('row','col') or ('layer','row','col')."""
     devices = devices if devices is not None else jax.devices()
     n = layers * pr * pc
     if len(devices) < n:
@@ -173,11 +169,15 @@ class DistSpMat:
         R[tid, within] = lr
         Cc[tid, within] = lc
         V[tid, within] = vals_s
+        # with a mesh the host arrays go straight to their tiles' devices
+        # (shard_put below); a default-device copy first would hold the
+        # whole matrix on jax.devices()[0]
+        put = jnp.asarray if mesh is None else np.asarray
         out = DistSpMat(
-            row=jnp.asarray(R.reshape(pr, pc, cap)),
-            col=jnp.asarray(Cc.reshape(pr, pc, cap)),
-            val=jnp.asarray(V.reshape((pr, pc, cap) + tuple(vdims))),
-            nnz=jnp.asarray(counts.reshape(pr, pc).astype(np.int32)),
+            row=put(R.reshape(pr, pc, cap)),
+            col=put(Cc.reshape(pr, pc, cap)),
+            val=put(V.reshape((pr, pc, cap) + tuple(vdims))),
+            nnz=put(counts.reshape(pr, pc).astype(np.int32)),
             shape=(int(M), int(N)), grid=(pr, pc),
             # the lexsort above sorted each tile by the requested key
             order=order)
@@ -355,11 +355,12 @@ class DistSpMat3D:
         R[tid, within] = lr
         Cc[tid, within] = lc
         V[tid, within] = vals_s
+        put = jnp.asarray if mesh is None else np.asarray  # as in DistSpMat
         out = DistSpMat3D(
-            row=jnp.asarray(R.reshape(L, q, q, cap)),
-            col=jnp.asarray(Cc.reshape(L, q, q, cap)),
-            val=jnp.asarray(V.reshape(L, q, q, cap)),
-            nnz=jnp.asarray(counts.reshape(L, q, q).astype(np.int32)),
+            row=put(R.reshape(L, q, q, cap)),
+            col=put(Cc.reshape(L, q, q, cap)),
+            val=put(V.reshape(L, q, q, cap)),
+            nnz=put(counts.reshape(L, q, q).astype(np.int32)),
             shape=(int(M), int(N)), grid=(L, q, q), dist=dist,
             order="row")  # lexsort above is (lr, lc) within tile
         if mesh is not None:

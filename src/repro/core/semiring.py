@@ -113,12 +113,13 @@ def semiring(add_op, add_identity, mul_op, *, tag=None, name="user") -> Semiring
 # Segment reduction under an arbitrary monoid
 # --------------------------------------------------------------------------
 
-# Optional accelerator backend (kernels/segreduce.py registers the Pallas
+# Accelerator backend (kernels/segreduce.py registers the Pallas
 # segmented-reduce kernel here). The backend is called first for tagged
 # monoids; returning None falls through to the pure-JAX paths below.
-# Resolution is lazy: the first tagged segment_reduce asks the kernels
-# layer to auto-register (TPU / REPRO_SEGREDUCE=pallas), so plain CPU runs
-# never pay the pallas import and keep XLA's native segment ops.
+# Resolution is lazy: the first tagged segment_reduce on a TPU registers
+# the compiled kernel, so CPU runs never pay the pallas import and keep
+# XLA's native segment ops. Tests register the interpreted kernel
+# themselves.
 _SEGREDUCE_BACKEND = None
 _SEGREDUCE_RESOLVED = False
 
@@ -137,15 +138,9 @@ def _resolve_segreduce_backend() -> None:
     if _SEGREDUCE_RESOLVED:
         return
     _SEGREDUCE_RESOLVED = True
-    import os
-    if os.environ.get("REPRO_SEGREDUCE", "").lower() not in ("1", "pallas") \
-            and jax.default_backend() != "tpu":
-        return                  # CPU/GPU: skip even the pallas import
-    try:
+    if jax.default_backend() == "tpu":
         from ..kernels import segreduce
-        segreduce.register()
-    except ImportError:  # pragma: no cover - pallas unavailable
-        pass
+        segreduce.register(interpret=False)
 
 
 def _segmented_scan_reduce(values: Array, seg_ids: Array, num_segments: int,

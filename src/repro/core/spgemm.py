@@ -72,7 +72,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..dist.compression import quantize_payload
 from ..obs import recorder as _obs
 from ..robust import audit as _audit
-from .compat import pvary, shard_map
+from .compat import shard_map
 from .coo import COO, SENTINEL
 from .dist import DistSpMat, DistSpMat3D, specs_of
 from .local_spgemm import _expand
@@ -366,14 +366,7 @@ def _local_spgemm_2d(a_tile: COO, b_tile: COO, sr, q, prod_cap, out_cap,
                                   a_scale)
     b_rot, bs_rot = _tile_permute(b_tile, axes, _cannon_perms(q, False),
                                   b_scale)
-    if mask is not None:
-        # loop-invariant closure of the scan bodies below: mark varying so
-        # newer-jax manual-axes checks accept the device-local mask arrays
-        mask = LocalMask(pvary(mask.keys, axes),
-                         None if mask.allow is None
-                         else pvary(mask.allow, axes),
-                         mask.complement, mask.order)
-    ok0 = pvary(jnp.bool_(True), axes)
+    ok0 = jnp.bool_(True)
 
     def rotate(at, as_, bt, bs_):
         at, as_ = _tile_permute(at, "col", _shift_perm(q, q, left=True), as_)
@@ -384,10 +377,6 @@ def _local_spgemm_2d(a_tile: COO, b_tile: COO, sr, q, prod_cap, out_cap,
         kacc, vacc, nacc = kv_empty(
             shape, out_cap, vals_dtype(sr, a_tile, b_tile, a_scale, b_scale),
             sr.add)
-        # constants entering a shard_map scan carry must be marked varying
-        # (newer jax; identity on 0.4.x — see compat.pvary)
-        kacc, vacc, nacc = (pvary(kacc, axes), pvary(vacc, axes),
-                            pvary(nacc, axes))
 
         def step(state, at, bt):
             kacc, vacc, nacc, ok = state

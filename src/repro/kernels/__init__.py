@@ -1,7 +1,8 @@
 """repro.kernels — Pallas TPU kernels for the compute hot-spots.
 
 Kernels (each validated in interpret mode against the pure-jnp oracle in
-ref.py; on-TPU they are swapped in via ops.py):
+ref.py, and compiled for a described v5e chip in tests/test_tpu_compile.py;
+every entry point takes ``interpret`` from its caller, with no default):
 
   semiring_matmul  dense-tile semiring contraction — the TPU realization of
                    the paper's "hash-table" local SpGEMM accumulator

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.compat import tpu_compiler_params
 
 
 def _kernel(cols_ref, vals_ref, x_ref, o_ref):
@@ -39,7 +38,7 @@ def _kernel(cols_ref, vals_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
 def bsr_spmm(block_cols, block_vals, x, *, bn: int = 128,
-             interpret: bool = True):
+             interpret: bool):
     """y = A @ x. block_cols: (R,K) i32; block_vals: (R,K,bm,bk);
     x: (n_cols, n) with n_cols % bk == 0. Returns (R*bm, n)."""
     R, K, bm, bk = block_vals.shape
@@ -68,7 +67,7 @@ def bsr_spmm(block_cols, block_vals, x, *, bn: int = 128,
                                    lambda r, j, k, cols: (r, 0, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((R, bm, n), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_cols, block_vals, x).reshape(R * bm, n)

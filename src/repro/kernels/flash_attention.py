@@ -17,7 +17,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -60,7 +59,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bkv", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bkv: int = 128, interpret: bool = True):
+                    bkv: int = 128, interpret: bool):
     """q/k/v: (B, S, H, d) — same-head attention (repeat KV for GQA first).
 
     Returns (B, S, H, d)."""
@@ -89,7 +88,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)

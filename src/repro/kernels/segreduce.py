@@ -13,6 +13,7 @@ streams the input past it:
   blocks whose id range cannot touch this output tile — for the sorted
   streams the merge engine produces, each input block intersects O(1)
   output tiles, so the sweep does O(N·bs + S·bn) work, not O(N·S).
+  Blocks are ``BLOCK`` = 1024 wide (the HBM tiling of a 1-D array).
 
 Per surviving (tile, block) pair the segment combine is a broadcast
 compare-and-reduce on the VPU (no scatter): hit[t, i] = (ids[i] == t),
@@ -31,8 +32,12 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..core.compat import tpu_compiler_params
+# Block width of the output tiles and input blocks: XLA tiles a 1-D 32-bit
+# array in HBM by 1024, and Mosaic refuses a narrower 1-D block of a
+# longer array.
+BLOCK = 1024
 
 _IDENT = dict(sum=0, min=float("inf"), max=float("-inf"))
 
@@ -41,8 +46,6 @@ def _extreme(tag: str, dtype) -> jnp.ndarray:
     """Accumulator fill: 0 for sum, the dtype extreme for min/max."""
     if tag == "sum":
         return jnp.zeros((), dtype)
-    if jnp.issubdtype(dtype, jnp.bool_):
-        return jnp.asarray(tag != "max", dtype)   # lor: False, land: True
     if jnp.issubdtype(dtype, jnp.integer):
         info = jnp.iinfo(dtype)
         return jnp.asarray(info.max if tag == "min" else info.min, dtype)
@@ -86,8 +89,8 @@ def _kernel(s_ref, v_ref, o_ref, t_ref, *, tag: str, bs: int):
                                              "identity", "bs", "bn",
                                              "interpret"))
 def segment_reduce_pallas(values, seg_ids, num_segments: int, tag: str,
-                          *, identity=None, bs: int = 256, bn: int = 256,
-                          interpret: bool = True):
+                          *, identity=None, bs: int = BLOCK, bn: int = BLOCK,
+                          interpret: bool):
     """Segmented reduce of a SORTED id stream under a tagged monoid.
 
     ids outside [0, num_segments) are dropped. Untouched segments hold
@@ -100,6 +103,13 @@ def segment_reduce_pallas(values, seg_ids, num_segments: int, tag: str,
     """
     assert values.ndim == 1, "kernel path is scalar-valued"
     assert tag in ("sum", "min", "max"), tag
+    if values.dtype == jnp.bool_:
+        # Mosaic has no bool reductions: reduce 0/1 as int32 (lor = max)
+        ident = (tag != "max") if identity is None else identity
+        out = segment_reduce_pallas(values.astype(jnp.int32), seg_ids,
+                                    num_segments, tag, identity=int(ident),
+                                    bs=bs, bn=bn, interpret=interpret)
+        return out.astype(jnp.bool_)
     n = values.shape[0]
     s = int(num_segments)
     if s == 0:
@@ -128,7 +138,7 @@ def segment_reduce_pallas(values, seg_ids, num_segments: int, tag: str,
                    pl.BlockSpec((bs,), lambda j, k: (j,))],
         out_shape=[jax.ShapeDtypeStruct((sp,), values.dtype),
                    jax.ShapeDtypeStruct((sp,), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ids, v)
@@ -144,6 +154,18 @@ def segment_reduce_pallas(values, seg_ids, num_segments: int, tag: str,
 # segment_reduce backend registration (core.semiring dispatch)
 # --------------------------------------------------------------------------
 
+# The sweep visits every (output tile, input block) pair, skipped or not.
+# Past this many grid steps the backend declines and XLA's segment ops
+# take the reduction; a sweep that visits only the blocks each tile needs
+# would lift the bound.
+MAX_GRID_STEPS = 1 << 20
+
+# Trace-time dispatch counts of the backend: segment_reduce calls the
+# kernel took ("compiled" / "interpret") and calls it declined for the
+# step bound ("declined"). A call is counted when its caller is traced.
+CALLS = {"compiled": 0, "interpret": 0, "declined": 0}
+
+
 def _backend(values, seg_ids, num_segments, tag, identity, *, interpret):
     """Adapter: returns None for inputs the kernel does not take, which
     makes ``segment_reduce`` fall through to its pure-JAX paths."""
@@ -158,19 +180,21 @@ def _backend(values, seg_ids, num_segments, tag, identity, *, interpret):
         if jnp.issubdtype(values.dtype, jnp.integer) and \
                 not math.isfinite(ident):
             return None                  # inf-identity monoid on int values
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return segment_reduce_pallas(values, seg_ids, int(num_segments), tag,
-                                 identity=ident, interpret=bool(interpret))
+    n, s = values.shape[0], int(num_segments)
+    if -(-s // BLOCK) * -(-n // BLOCK) > MAX_GRID_STEPS:
+        CALLS["declined"] += 1
+        return None
+    CALLS["interpret" if interpret else "compiled"] += 1
+    return segment_reduce_pallas(values, seg_ids, s, tag, identity=ident,
+                                 interpret=interpret)
 
 
-def register(*, interpret: bool | None = None) -> None:
+def register(*, interpret: bool) -> None:
     """Install the Pallas kernel behind ``core.semiring.segment_reduce``.
 
-    ``interpret=None`` resolves at call time: compiled on TPU, interpreter
-    elsewhere (the interpreter is for validation, not speed — automatic
-    registration, via semiring's lazy backend resolution, happens only on
-    TPU or under REPRO_SEGREDUCE=pallas).
+    ``interpret=False`` compiles it for the TPU (what the lazy automatic
+    registration does there); ``interpret=True`` runs the Pallas
+    interpreter, a validation tool for CPU tests, not a fast path.
     """
     from ..core import semiring
     semiring.register_segment_reduce_backend(

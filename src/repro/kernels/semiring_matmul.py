@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.compat import tpu_compiler_params
 
 IDENTITY = dict(plus_times=0.0, min_plus=jnp.inf, max_min=-jnp.inf,
                 or_and=False)
@@ -56,7 +55,7 @@ def _kernel(a_ref, b_ref, o_ref, *, kind: str, bk: int):
 @functools.partial(jax.jit, static_argnames=("kind", "bm", "bn", "bk",
                                              "interpret"))
 def semiring_matmul(a, b, *, kind: str = "plus_times", bm: int = 128,
-                    bn: int = 128, bk: int = 128, interpret: bool = True):
+                    bn: int = 128, bk: int = 128, interpret: bool):
     """C = A ⊕.⊗ B with MXU-aligned VMEM tiling. A: (M,K), B: (K,N)."""
     M, K = a.shape
     K2, N = b.shape
@@ -80,7 +79,7 @@ def semiring_matmul(a, b, *, kind: str = "plus_times", bm: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

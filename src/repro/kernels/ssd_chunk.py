@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core.compat import tpu_compiler_params
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref):
@@ -48,7 +47,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk(xc, dtc, A, Bc, Cc, *, interpret: bool = True):
+def ssd_chunk(xc, dtc, A, Bc, Cc, *, interpret: bool):
     """Intra-chunk SSD over all (batch·chunk, head) pairs.
 
     xc: (G, q, H, P); dtc: (G, q, H); A: (H,); Bc/Cc: (G, q, N) where
@@ -75,7 +74,7 @@ def ssd_chunk(xc, dtc, A, Bc, Cc, *, interpret: bool = True):
             jax.ShapeDtypeStruct((G, q, H, P), jnp.float32),
             jax.ShapeDtypeStruct((G, H, P, N), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xc, dtc, A, Bc, Cc)

@@ -24,6 +24,7 @@ import traceback  # noqa: E402
 import jax        # noqa: E402
 import jax.numpy as jnp                       # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P   # noqa: E402
+from .cache import use_compile_cache            # noqa: E402
 
 
 def build_cell(arch: str, shape_name: str, *, multi_pod: bool,
@@ -159,6 +160,11 @@ def _cache_specs(model, plan, caches):
     return specs
 
 
+# The production meshes the dry-run compiles for (on forced host devices)
+# are TPU v5e pods: their roofline terms use that chip's peaks.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
 def run_cell(arch, shape_name, *, multi_pod, out_dir=None, overrides=None,
              verbose=True, probes=True, model_opts=None, accum=1,
              serve_bf16=False, tag_extra=""):
@@ -197,11 +203,13 @@ def run_cell(arch, shape_name, *, multi_pod, out_dir=None, overrides=None,
         raw = extrapolate_raw(raws[0], raws[1], reps)
         rf = terms_from_raw(raw, n_devices=meta["n_devices"],
                             model_flops=meta["model_flops"],
+                            device_kind=TARGET_DEVICE_KIND,
                             memory_stats=compiled.memory_analysis())
         rf["scanned_raw"] = raw_metrics(compiled)
         rf["probe_raws"] = raws
     else:
         rf = roofline_terms(compiled, n_devices=meta["n_devices"],
+                            device_kind=TARGET_DEVICE_KIND,
                             model_flops=meta["model_flops"])
     result = dict(meta=meta, lower_s=t_lower, compile_s=t_compile, **rf)
     if verbose:
@@ -226,6 +234,7 @@ def run_cell(arch, shape_name, *, multi_pod, out_dir=None, overrides=None,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

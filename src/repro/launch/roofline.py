@@ -14,8 +14,9 @@ result shape into wire bytes per device:
 with g = participants (parsed from replica_groups). The collective term
 divides by ONE ICI link (50 GB/s): a deliberately conservative single-link
 serialization model — multi-link overlap is credited in §Perf only when
-the schedule provably uses disjoint axes. TPU v5e constants:
-197 TFLOP/s bf16, 819 GB/s HBM.
+the schedule provably uses disjoint axes. The peaks come from ``PEAKS``,
+keyed by the ``device_kind`` jax reports; a kind that is not in the table
+is an error, never a default.
 """
 from __future__ import annotations
 
@@ -23,9 +24,19 @@ import dataclasses
 import json
 import re
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+# Per-chip peaks by device_kind. TPU v5e ("TPU v5 lite"): 197 TFLOP/s bf16
+# and 819 GB/s HBM (Google Cloud documentation, "TPU v5e"); the collective
+# term uses one 50 GB/s ICI link (the serialization model above).
+PEAKS = {"TPU v5 lite": dict(flops=197e12, hbm_bw=819e9, link_bw=50e9)}
+
+
+def peaks(device_kind: str) -> dict:
+    """The ``PEAKS`` entry for ``device_kind``; raises for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no roofline peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3": 1,
                 "f8e5m2": 1, "s64": 8, "u64": 8, "s32": 4, "u32": 4,
@@ -93,12 +104,8 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions: older releases return
-    a one-element list of dicts, newer ones the dict itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """compiled.cost_analysis() as a dict (empty when XLA reports none)."""
+    return compiled.cost_analysis() or {}
 
 
 def raw_metrics(compiled) -> dict:
@@ -137,14 +144,16 @@ def extrapolate_raw(r1: dict, r2: dict, reps: int) -> dict:
 
 
 def terms_from_raw(raw: dict, *, n_devices: int, model_flops: float,
-                   memory_stats=None) -> dict:
+                   device_kind: str, memory_stats=None) -> dict:
+    pk = peaks(device_kind)
     flops, bytes_accessed = raw["flops"], raw["bytes"]
-    t_compute = flops / PEAK_FLOPS
-    t_memory = bytes_accessed / HBM_BW
-    t_coll = raw["coll"]["total"] / LINK_BW
+    t_compute = flops / pk["flops"]
+    t_memory = bytes_accessed / pk["hbm_bw"]
+    t_coll = raw["coll"]["total"] / pk["link_bw"]
     terms = dict(compute=t_compute, memory=t_memory, collective=t_coll)
     bound = max(t_compute, t_memory, t_coll)
     result = dict(
+        device_kind=device_kind,
         per_device_flops=flops,
         per_device_bytes=bytes_accessed,
         collective=raw["coll"],
@@ -155,7 +164,7 @@ def terms_from_raw(raw: dict, *, n_devices: int, model_flops: float,
         hlo_flops_global=flops * n_devices,
         useful_flops_ratio=(model_flops / (flops * n_devices))
         if flops and model_flops else None,
-        roofline_fraction=(model_flops / n_devices / PEAK_FLOPS) / bound
+        roofline_fraction=(model_flops / n_devices / pk["flops"]) / bound
         if bound and model_flops else None)
     if memory_stats is not None:
         ma = memory_stats
@@ -168,39 +177,9 @@ def terms_from_raw(raw: dict, *, n_devices: int, model_flops: float,
     return result
 
 
-def roofline_terms(compiled, *, n_devices: int, model_flops: float = 0.0):
+def roofline_terms(compiled, *, n_devices: int, device_kind: str,
+                   model_flops: float = 0.0):
     """Compute the three roofline terms from a compiled executable."""
-    ca = cost_dict(compiled)
-    flops = float(ca.get("flops", 0.0))
-    bytes_accessed = float(ca.get("bytes accessed", 0.0))
-    coll = collective_bytes(compiled.as_text())
-    t_compute = flops / PEAK_FLOPS
-    t_memory = bytes_accessed / HBM_BW
-    t_coll = coll["total"] / LINK_BW
-    terms = dict(compute=t_compute, memory=t_memory, collective=t_coll)
-    dominant = max(terms, key=terms.get)
-    bound = max(t_compute, t_memory, t_coll)
-    ma = compiled.memory_analysis()
-    result = dict(
-        per_device_flops=flops,
-        per_device_bytes=bytes_accessed,
-        collective=coll,
-        terms_seconds=terms,
-        dominant=dominant,
-        step_time_lower_bound_s=bound,
-        model_flops_global=model_flops,
-        hlo_flops_global=flops * n_devices,
-        useful_flops_ratio=(model_flops / (flops * n_devices))
-        if flops and model_flops else None,
-        roofline_fraction=(model_flops / n_devices / PEAK_FLOPS) / bound
-        if bound and model_flops else None,
-        memory_per_device=dict(
-            args=ma.argument_size_in_bytes,
-            out=ma.output_size_in_bytes,
-            temp=ma.temp_size_in_bytes,
-            alias=ma.alias_size_in_bytes,
-            total_transient=ma.argument_size_in_bytes +
-            ma.output_size_in_bytes + ma.temp_size_in_bytes -
-            ma.alias_size_in_bytes),
-    )
-    return result
+    return terms_from_raw(raw_metrics(compiled), n_devices=n_devices,
+                          model_flops=model_flops, device_kind=device_kind,
+                          memory_stats=compiled.memory_analysis())

@@ -19,9 +19,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from .cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k")

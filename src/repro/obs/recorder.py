@@ -58,23 +58,10 @@ _MAX_RECORDS = 1_000_000         # backstop against unbounded growth
 # tracing guard (second line of defense behind host-boundary placement)
 # --------------------------------------------------------------------------
 
-_trace_pred = None
-
-
 def tracing() -> bool:
     """True when called from inside jax tracing (jit/shard_map/scan)."""
-    global _trace_pred
-    if _trace_pred is None:
-        try:
-            from jax.core import trace_state_clean
-            _trace_pred = trace_state_clean
-        except Exception:                      # pragma: no cover - old jax
-            try:
-                from jax._src.core import trace_state_clean
-                _trace_pred = trace_state_clean
-            except Exception:
-                _trace_pred = lambda: True
-    return not _trace_pred()
+    from jax._src.core import trace_state_clean
+    return not trace_state_clean()
 
 
 def enabled() -> bool:

@@ -45,3 +45,10 @@ def test_registry_cell_accounting():
     assert not ok and "sub-quadratic" in why
     ok, _ = cell_valid("jamba-1.5-large-398b", "long_500k")
     assert ok
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.launch.roofline import peaks
+    assert peaks("TPU v5 lite")["flops"] == 197e12
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        peaks("cpu")

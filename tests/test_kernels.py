@@ -193,7 +193,7 @@ class TestSegReduce:
 
     @pytest.mark.parametrize("tag", ["sum", "min", "max"])
     @pytest.mark.parametrize("n,s", [(1000, 300), (64, 8), (512, 512),
-                                     (300, 1)])
+                                     (300, 1), (5000, 3000)])
     def test_vs_xla(self, tag, n, s):
         rng = np.random.default_rng(0)
         ids = jnp.asarray(np.sort(rng.integers(0, s, n)).astype(np.int32))
@@ -222,6 +222,22 @@ class TestSegReduce:
                                     3, "min", interpret=True)
         np.testing.assert_array_equal(np.asarray(got),
                                       [3, 2**31 - 1, 5])
+
+    @pytest.mark.parametrize("tag", ["min", "max"])
+    def test_bool_dtype(self, tag):
+        """BOOLEAN's LOR ("max") and LAND ("min") on bool streams."""
+        rng = np.random.default_rng(3)
+        ids = np.sort(rng.integers(0, 40, 300)).astype(np.int32)
+        ids[ids == 7] = 8                      # segment 7 stays untouched
+        v = rng.random(300) < 0.2
+        got = segment_reduce_pallas(jnp.asarray(v), jnp.asarray(ids), 40,
+                                    tag, identity=tag == "min",
+                                    interpret=True)
+        red = np.logical_or if tag == "max" else np.logical_and
+        want = np.array([red.reduce(v[ids == t]) if (ids == t).any()
+                         else tag == "min" for t in range(40)])
+        assert got.dtype == jnp.bool_
+        np.testing.assert_array_equal(np.asarray(got), want)
 
     def test_registered_backend_serves_segment_reduce(self):
         from repro.core import semiring

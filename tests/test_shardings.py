@@ -14,8 +14,8 @@ from repro.dist.shardings import (ShardingError, ShardingPlan,
 from repro.launch.mesh import make_plan
 from repro.models.model import init_param_specs, param_shapes
 
-MESH_2D = AbstractMesh((("data", 16), ("model", 16)))
-MESH_3D = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH_2D = AbstractMesh((16, 16), ("data", "model"))
+MESH_3D = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def walk(shapes, specs, prefix=""):
@@ -101,11 +101,11 @@ def test_indivisible_dim_fails_loudly():
 def test_plan_derived_from_mesh_shape():
     """dp_size / model_size follow the mesh — no hard-coded 32/16/16."""
     cfg = get_config("granite-3-2b")
-    small = AbstractMesh((("data", 4), ("model", 2)))
+    small = AbstractMesh((4, 2), ("data", "model"))
     plan = make_plan(cfg, mesh=small)
     assert plan.dp_size == 4 and plan.model_size == 2
     assert plan.dp_axes == ("data",) and plan.fsdp_axes == ("data",)
-    tri = AbstractMesh((("pod", 3), ("data", 4), ("model", 2)))
+    tri = AbstractMesh((3, 4, 2), ("pod", "data", "model"))
     plan3 = make_plan(cfg, mesh=tri)
     assert plan3.dp_size == 12 and plan3.dp_axes == ("pod", "data")
     assert plan3.fsdp_axes == ("data",)      # pod stays pure DP
@@ -114,7 +114,7 @@ def test_plan_derived_from_mesh_shape():
     assert make_plan(cfg).dp_size == 16
     assert make_plan(cfg, multi_pod=True).dp_size == 32
     with pytest.raises(ValueError, match="model"):
-        make_plan(cfg, mesh=AbstractMesh((("a", 4), ("b", 2))))
+        make_plan(cfg, mesh=AbstractMesh((4, 2), ("a", "b")))
 
 
 def test_context_parallel_cache_layout():
@@ -171,7 +171,7 @@ def test_serving_layout_drops_fsdp():
 def test_smoke_configs_shard_on_small_mesh():
     """The same rules hold for the reduced smoke configs on a test-sized
     mesh (every smoke dim divides 2×2)."""
-    mesh = AbstractMesh((("data", 2), ("model", 2)))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     for arch in ARCHS:
         cfg = get_smoke(arch)
         plan = make_plan(cfg, mesh=mesh)
