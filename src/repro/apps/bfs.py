@@ -51,10 +51,12 @@ def bfs_levels(a: DistSpMat, source: int, *, mesh: Mesh,
                                lambda cur, xv: jnp.zeros_like(cur))
     level = 0
     max_iters = max_iters or n
-    while int(spvec_nnz(frontier)) > 0 and level < max_iters:
+    # one device→host read of the frontier's size a level: the loop
+    # condition, and the level span's attribute
+    frontier_nnz = _frontier_nnz(frontier)
+    while frontier_nnz > 0 and level < max_iters:
         level += 1
-        with _obs.span("bfs.level", level=level,
-                       frontier_nnz=int(spvec_nnz(frontier))):
+        with _obs.span("bfs.level", level=level, frontier_nnz=frontier_nnz):
             fcol = transpose_spvec_layout(frontier, mesh=mesh)
             # visited vertices (level >= 0) as a complement mask: the fused
             # kernel emits ONLY unvisited neighbors — no post-filter pass
@@ -66,4 +68,10 @@ def bfs_levels(a: DistSpMat, source: int, *, mesh: Mesh,
             levels = vec_scatter_spvec(
                 levels, nxt, lambda cur, xv: jnp.full_like(cur, level))
             frontier = nxt
+        frontier_nnz = _frontier_nnz(frontier)
     return levels.to_global().astype(np.int32)
+
+
+def _frontier_nnz(frontier: DistSpVec) -> int:
+    _obs.counter_add("host.sync.bfs.frontier")
+    return int(spvec_nnz(frontier))

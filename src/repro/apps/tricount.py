@@ -29,4 +29,5 @@ def triangle_count(a: DistSpMat, *, mesh: Mesh, prod_cap: int | None = None,
         b, _plan = spgemm_planned(l, l, ARITHMETIC, mesh=mesh,
                                   mask=structural(l),
                                   prod_cap=prod_cap, out_cap=out_cap)
+        _obs.counter_add("host.sync.mat_sum")
         return int(mat_sum(b))

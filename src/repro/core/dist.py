@@ -66,6 +66,17 @@ def make_grid(pr: int, pc: int, layers: int = 1,
 # 2D distributed sparse matrix
 # --------------------------------------------------------------------------
 
+def _fetch(*arrays) -> list[np.ndarray]:
+    """The arrays on the host: one blocking device→host read each, counted
+    as ``host.sync.fetch``, and their bytes, from shapes and dtypes, as
+    ``fetch.bytes``."""
+    if _obs.enabled():
+        _obs.counter_add("host.sync.fetch", len(arrays))
+        _obs.counter_add("fetch.bytes", sum(
+            math.prod(x.shape) * np.dtype(x.dtype).itemsize for x in arrays))
+    return [np.asarray(x) for x in arrays]
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class DistSpMat:
@@ -187,13 +198,15 @@ class DistSpMat:
             out = shard_put(out, mesh)
         return out
 
+    @_obs.timed("dist.fetch")
     def to_global_coo(self):
         """Gather to host as (rows, cols, vals) in global int64 coords."""
         pr, pc, cap = self.pr, self.pc, self.cap
-        R = np.asarray(self.row).reshape(pr, pc, cap)
-        C = np.asarray(self.col).reshape(pr, pc, cap)
-        V = np.asarray(self.val).reshape((pr, pc, cap) + self.val.shape[3:])
-        Nz = np.asarray(self.nnz).reshape(pr, pc)
+        R, C, V, Nz = _fetch(self.row, self.col, self.val, self.nnz)
+        R = R.reshape(pr, pc, cap)
+        C = C.reshape(pr, pc, cap)
+        V = V.reshape((pr, pc, cap) + self.val.shape[3:])
+        Nz = Nz.reshape(pr, pc)
         rows, cols, vals = [], [], []
         for i in range(pr):
             for j in range(pc):
@@ -452,9 +465,10 @@ class DistVec:
             v = shard_put(v, mesh)
         return v
 
+    @_obs.timed("dist.fetch")
     def to_global(self) -> np.ndarray:
         pr, pc = self.grid
-        d = np.asarray(self.data)
+        (d,) = _fetch(self.data)
         vb = self.vb
         xp = np.empty((pr * pc, vb) + d.shape[3:], d.dtype)
         for i in range(pr):
@@ -522,11 +536,10 @@ class DistSpVec:
             v = shard_put(v, mesh)
         return v
 
+    @_obs.timed("dist.fetch")
     def to_global(self):
         pr, pc = self.grid
-        I = np.asarray(self.idx)
-        V = np.asarray(self.val)
-        Nz = np.asarray(self.nnz)
+        I, V, Nz = _fetch(self.idx, self.val, self.nnz)
         idxs, vals = [], []
         for i in range(pr):
             for j in range(pc):

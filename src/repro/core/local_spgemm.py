@@ -27,6 +27,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import recorder as _obs
 from .coo import COO, SENTINEL, column_range, row_range
 from .semiring import ARITHMETIC, Monoid, Semiring, dense_semiring_matmul
 
@@ -49,6 +50,7 @@ def spgemm_flops(a: COO, b: COO) -> Array:
     return jnp.sum(jnp.where(b.mask(), end - start, 0))
 
 
+@_obs.timed("expand")
 def _expand(a: COO, b: COO, sr: Semiring, prod_cap: int):
     """ESC expansion: one slot per scalar multiply (O(flops) work).
 

@@ -43,6 +43,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs import recorder as _obs
 from .coo import COO, SENTINEL
 from .dist import DistSpMat, DistSpMat3D, DistVec
 from .merge import _unpack, key_dtype, pack_keys
@@ -97,6 +98,7 @@ def local_mask(tile: COO, *, pred: Callable | None = None,
     return LocalMask(keys, allow, complement, order)
 
 
+@_obs.timed("mask_probe")
 def mask_member(keys: Array, m: LocalMask) -> Array:
     """Vectorized sorted-membership probe.
 
@@ -246,4 +248,5 @@ def mask_allowed_count(mask: MaskSpec) -> int | None:
     member = jnp.asarray(mask.pred(mask.vec.data))
     if mask.complement:
         member = ~member
+    _obs.counter_add("host.sync.plan.mask_allowed")
     return int(jax.device_get(jnp.sum(member)))

@@ -82,8 +82,15 @@ def _pow2(x: float, lo: int = 64) -> int:
     return max(lo, 1 << math.ceil(math.log2(max(float(x), 1.0))))
 
 
+def _host_read(site: str, x):
+    """``jax.device_get(x)``: a blocking device→host read of the planner,
+    counted as ``host.sync.plan.<site>``."""
+    _obs.counter_add("host.sync.plan." + site)
+    return jax.device_get(x)
+
+
 def _host_nnz(a) -> np.ndarray:
-    return np.asarray(jax.device_get(a.nnz), np.float64)
+    return np.asarray(_host_read("nnz", a.nnz), np.float64)
 
 
 # --------------------------------------------------------------------------
@@ -246,6 +253,7 @@ def plan_spgemm(a: DistSpMat, b: DistSpMat | None = None, *,
                       compress=compress)
 
 
+@_obs.timed("plan.spgemm")
 def spgemm(a: DistSpMat, b: DistSpMat | None = None,
            sr: Semiring = ARITHMETIC, *, mesh,
            plan: SpGEMMPlan | None = None,
@@ -301,7 +309,7 @@ def spgemm(a: DistSpMat, b: DistSpMat | None = None,
                     f"SpGEMM attempt {p.attempts} failed audit at "
                     f"{err.site}: {err} — retrying from pristine inputs "
                     f"({audit_fails}/{MAX_AUDIT_RETRIES})",
-                    RuntimeWarning, stacklevel=2)
+                    RuntimeWarning, stacklevel=3)
                 if timeout:
                     # a deadline trip means a straggling peer, not a flipped
                     # bit — give the topology time to heal before hammering
@@ -328,6 +336,7 @@ def spgemm(a: DistSpMat, b: DistSpMat | None = None,
                 rung, p, a, b, safety, cur_mask, post_mask)
             continue
         ok = _faults.flip_ok("plan.spgemm.ok", ok)
+        _obs.counter_add("host.sync.plan.ok")
         if bool(jnp.all(ok)):
             if post_mask is not None:
                 c = _recover.postfilter_2d(c, post_mask, sr, mesh=mesh)
@@ -541,6 +550,7 @@ def plan_spmspv(a: DistSpMat, frontier_nnz: int, *, safety: float = 4.0,
         density=density)
 
 
+@_obs.timed("plan.spmspv")
 def spmspv(a: DistSpMat, x: DistSpVec, sr: Semiring, *, mesh,
            plan: SpMSpVPlan | None = None,
            prod_cap: int | None = None, out_cap: int | None = None,
@@ -561,7 +571,8 @@ def spmspv(a: DistSpMat, x: DistSpVec, sr: Semiring, *, mesh,
             from .mask import mask_allowed_count
             allowed = mask_allowed_count(mask)
         plan = plan_spmspv(
-            a, int(jax.device_get(jnp.sum(x.nnz))), safety=safety,
+            a, int(_host_read("frontier_nnz", jnp.sum(x.nnz))),
+            safety=safety,
             prod_cap=prod_cap, out_cap=out_cap, variant=variant, merge=merge,
             add_tag=sr.add.tag, mask_allowed=allowed)
     p = plan
@@ -586,7 +597,7 @@ def spmspv(a: DistSpMat, x: DistSpVec, sr: Semiring, *, mesh,
                     f"SpMSpV attempt {p.attempts} failed audit at "
                     f"{err.site}: {err} — retrying from pristine inputs "
                     f"({audit_fails}/{MAX_AUDIT_RETRIES})",
-                    RuntimeWarning, stacklevel=2)
+                    RuntimeWarning, stacklevel=3)
                 if timeout:
                     _deadline.backoff_sleep(err.site, audit_fails)
                 p = dataclasses.replace(p, attempts=p.attempts + 1)
@@ -607,6 +618,7 @@ def spmspv(a: DistSpMat, x: DistSpVec, sr: Semiring, *, mesh,
                 rung, p, a, x, safety, sr, cur_mask, post_mask)
             continue
         ok = _faults.flip_ok("plan.spmspv.ok", ok)
+        _obs.counter_add("host.sync.plan.ok")
         if bool(jnp.all(ok)):
             if post_mask is not None:
                 y = _recover.postfilter_spvec(y, post_mask)
@@ -637,7 +649,7 @@ def _spmspv_take_rung(rung, p, a, x, safety, sr, cur_mask, post_mask):
     p = dataclasses.replace(p, attempts=p.attempts + 1)
     if rung != "postfilter":
         return p, cur_mask, post_mask
-    fresh = plan_spmspv(a, int(jax.device_get(jnp.sum(x.nnz))),
+    fresh = plan_spmspv(a, int(_host_read("frontier_nnz", jnp.sum(x.nnz))),
                         safety=safety, variant=p.variant, merge=p.merge,
                         add_tag=sr.add.tag)
     p = dataclasses.replace(
@@ -685,8 +697,8 @@ def plan_local_spgemm(a: COO, b: COO, *, safety: float = 1.25,
     compression-ratio hybrid.
     """
     m, n = a.shape[0], b.shape[1]
-    fl = int(jax.device_get(spgemm_flops(a, b)))
-    ratio = float(jax.device_get(compression_ratio(a, b)))
+    fl = int(_host_read("flops", spgemm_flops(a, b)))
+    ratio = float(_host_read("ratio", compression_ratio(a, b)))
     prod_cap = _pow2(max(fl, 1) * safety)
     out_bound = min(max(fl, 1), m * n)
     if mask_nnz is not None:
@@ -710,7 +722,7 @@ def plan_local_spmspv(a: COO, frontier_nnz: int, *,
                       safety: float = 4.0) -> LocalSpMSpVPlan:
     n = max(a.shape[1], 1)
     density = max(int(frontier_nnz), 1) / n
-    nnz = int(jax.device_get(a.nnz))
+    nnz = int(_host_read("nnz", a.nnz))
     est = max(nnz * density, 1.0)
     prod_cap = min(_pow2(safety * est), _pow2(max(nnz, 1)))
     out_cap = min(_pow2(safety * est), _pow2(a.shape[0]))

@@ -20,6 +20,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from ..obs import recorder as _obs
+
 Array = jax.Array
 
 _FAST_TAGS = ("sum", "min", "max")
@@ -174,6 +176,7 @@ def _segmented_scan_reduce(values: Array, seg_ids: Array, num_segments: int,
     return out
 
 
+@_obs.timed("segreduce")
 def segment_reduce(values: Array, seg_ids: Array, num_segments: int,
                    monoid: Monoid, *, sorted_ids: bool = False) -> Array:
     """Reduce ``values`` by ``seg_ids`` under ``monoid``.

@@ -150,10 +150,11 @@ def _tile_permute(tile: COO, axes, perm, scale=None):
     Returns (tile, scale); scale is None when no scale was passed.
     """
     fields = (tile.row, tile.col, tile.val, tile.nnz)
-    if scale is None:
-        r, c, v, n = jax.lax.ppermute(fields, axes, perm)
-        return COO(r, c, v, n, tile.shape, tile.order), None
-    r, c, v, n, s = jax.lax.ppermute(fields + (scale,), axes, perm)
+    with _obs.span("exchange"):
+        if scale is None:
+            r, c, v, n = jax.lax.ppermute(fields, axes, perm)
+            return COO(r, c, v, n, tile.shape, tile.order), None
+        r, c, v, n, s = jax.lax.ppermute(fields + (scale,), axes, perm)
     return COO(r, c, v, n, tile.shape, tile.order), s
 
 
@@ -283,10 +284,13 @@ def _local_spgemm_2d(a_tile: COO, b_tile: COO, sr, q, prod_cap, out_cap,
     if schedule == "alltoall":
         # gather my process row of A and process column of B (the broadcast
         # formulation; all stages' operands live simultaneously)
-        ar = jax.tree.map(lambda x: jax.lax.all_gather(x, "col"), a_tile)
-        bc = jax.tree.map(lambda x: jax.lax.all_gather(x, "row"), b_tile)
-        asg = None if a_scale is None else jax.lax.all_gather(a_scale, "col")
-        bsg = None if b_scale is None else jax.lax.all_gather(b_scale, "row")
+        with _obs.span("exchange"):
+            ar = jax.tree.map(lambda x: jax.lax.all_gather(x, "col"), a_tile)
+            bc = jax.tree.map(lambda x: jax.lax.all_gather(x, "row"), b_tile)
+            asg = (None if a_scale is None
+                   else jax.lax.all_gather(a_scale, "col"))
+            bsg = (None if b_scale is None
+                   else jax.lax.all_gather(b_scale, "row"))
         if not overlap:
             # bulk-synchronous: every stage's operands must land before any
             # local multiply starts
@@ -326,20 +330,21 @@ def _local_spgemm_2d(a_tile: COO, b_tile: COO, sr, q, prod_cap, out_cap,
         gs = [s for s in range(q) if sched[s] == "gather"]
         eag_a = eag_b = None
         if gs:
-            eag_a = jax.lax.psum(jax.tree.map(
-                lambda *xs: jnp.stack(xs),
-                *[sel(apay, ci, s) for s in gs]), "col")
-            eag_b = jax.lax.psum(jax.tree.map(
-                lambda *xs: jnp.stack(xs),
-                *[sel(bpay, ri, s) for s in gs]), "row")
+            stack = lambda *xs: jnp.stack(xs)
+            ga = jax.tree.map(stack, *[sel(apay, ci, s) for s in gs])
+            gb = jax.tree.map(stack, *[sel(bpay, ri, s) for s in gs])
+            with _obs.span("exchange"):
+                eag_a = jax.lax.psum(ga, "col")
+                eag_b = jax.lax.psum(gb, "row")
 
         def fetch(s):
             if s in gs:
                 i = gs.index(s)
                 return (jax.tree.map(lambda x: x[i], eag_a),
                         jax.tree.map(lambda x: x[i], eag_b))
-            return (jax.lax.psum(sel(apay, ci, s), "col"),
-                    jax.lax.psum(sel(bpay, ri, s), "row"))
+            sa, sb = sel(apay, ci, s), sel(bpay, ri, s)
+            with _obs.span("exchange"):
+                return jax.lax.psum(sa, "col"), jax.lax.psum(sb, "row")
 
         outs = []
         cur = fetch(0)
@@ -620,9 +625,10 @@ def spgemm_3d(a3: DistSpMat3D, b3: DistSpMat3D, sr: Semiring = ARITHMETIC, *,
         if mt:
             # assemble the FULL C-tile mask from the L csub sub-pieces:
             # sub-piece l covers tile columns [l·kbl, (l+1)·kbl)
-            mrow = jax.lax.all_gather(mt[0].row.reshape(-1), "layer")
-            mcol = jax.lax.all_gather(mt[0].col.reshape(-1), "layer")
-            mval = jax.lax.all_gather(mt[0].val.reshape(-1), "layer")
+            with _obs.span("exchange"):
+                mrow = jax.lax.all_gather(mt[0].row.reshape(-1), "layer")
+                mcol = jax.lax.all_gather(mt[0].col.reshape(-1), "layer")
+                mval = jax.lax.all_gather(mt[0].val.reshape(-1), "layer")
             fcol = jnp.where(
                 mcol != SENTINEL,
                 mcol + jnp.arange(L, dtype=jnp.int32)[:, None] * kbl,
@@ -674,9 +680,10 @@ def spgemm_3d(a3: DistSpMat3D, b3: DistSpMat3D, sr: Semiring = ARITHMETIC, *,
             # one fused tree-level all-to-all, issued as soon as the radix
             # placement finishes — XLA can overlap it with the argsort of
             # the next shard_map program and fuses three launches into one
-            buf_r, buf_c, buf_v = jax.lax.all_to_all(
-                (buf_r.reshape(L, cap_l), buf_c.reshape(L, cap_l),
-                 buf_v.reshape(L, cap_l)), "layer", 0, 0, tiled=False)
+            with _obs.span("exchange"):
+                buf_r, buf_c, buf_v = jax.lax.all_to_all(
+                    (buf_r.reshape(L, cap_l), buf_c.reshape(L, cap_l),
+                     buf_v.reshape(L, cap_l)), "layer", 0, 0, tiled=False)
             buf_r = buf_r.reshape(L * cap_l)
             buf_c = buf_c.reshape(L * cap_l)
             buf_v = buf_v.reshape(L * cap_l)
@@ -687,8 +694,10 @@ def spgemm_3d(a3: DistSpMat3D, b3: DistSpMat3D, sr: Semiring = ARITHMETIC, *,
                 (buf_r, buf_c, buf_v))
 
             def a2a(x):
-                return jax.lax.all_to_all(x.reshape(L, cap_l), "layer", 0, 0,
-                                          tiled=False).reshape(L * cap_l)
+                with _obs.span("exchange"):
+                    x = jax.lax.all_to_all(x.reshape(L, cap_l), "layer", 0,
+                                           0, tiled=False)
+                return x.reshape(L * cap_l)
 
             buf_r, buf_c, buf_v = a2a(buf_r), a2a(buf_c), a2a(buf_v)
         my_layer = jax.lax.axis_index("layer")

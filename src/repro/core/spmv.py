@@ -136,7 +136,8 @@ def spmspv(a: DistSpMat, x: DistSpVec, sr: Semiring = ARITHMETIC, *,
                 member = ~member
             # process row i's pieces j=0..pc-1 are exactly the tile's row
             # range [i*mb, (i+1)*mb) in j order (layout 'row')
-            allow = jax.lax.all_gather(member, "col", tiled=True)  # (mb,)
+            with _obs.span("exchange"):
+                allow = jax.lax.all_gather(member, "col", tiled=True)
         # gather the sparse pieces of column block j (localize to block)
         xi_l = xi.reshape(-1)
         xv_l = xv.reshape(-1)
@@ -145,21 +146,24 @@ def spmspv(a: DistSpMat, x: DistSpVec, sr: Semiring = ARITHMETIC, *,
         i_in_blk = jax.lax.axis_index("row")
         vb_in = a.nb // pr
         xi_blk = jnp.where(xi_l != SENTINEL, xi_l + i_in_blk * vb_in, SENTINEL)
-        gi = jax.lax.all_gather(xi_blk, "row", tiled=True)   # (pr*cap_x,)
-        gv = jax.lax.all_gather(xv_l, "row", tiled=True)
-        gn = jax.lax.psum(xn_l, "row")
+        with _obs.span("exchange"):
+            gi = jax.lax.all_gather(xi_blk, "row", tiled=True)  # (pr*cap_x,)
+            gv = jax.lax.all_gather(xv_l, "row", tiled=True)
+            gn = jax.lax.psum(xn_l, "row")
         # compact: local spmspv handles interleaved padding via mask->cnt=0
         # trick: treat gathered arrays as a sparse vector with nnz=total but
         # padding interleaved — _expand masks by index<nnz, so compact first
         order = jnp.argsort(gi == SENTINEL, stable=True)
         gi, gv = gi[order], gv[order]
-        (yi, yv, yn), ok = local_fn(tile, gi, gv, gn, sr,
-                                    prod_cap=prod_cap, out_cap=out_cap,
-                                    allow=allow)
+        with _obs.span("spmspv.local"):
+            (yi, yv, yn), ok = local_fn(tile, gi, gv, gn, sr,
+                                        prod_cap=prod_cap, out_cap=out_cap,
+                                        allow=allow)
         if merge == "dense" and sr.add.tag == "sum":
             dense = L.spvec_to_dense(yi, yv, mb, zero=0)
-            piece = jax.lax.psum_scatter(dense, "col", scatter_dimension=0,
-                                         tiled=True)
+            with _obs.span("exchange"):
+                piece = jax.lax.psum_scatter(dense, "col",
+                                             scatter_dimension=0, tiled=True)
             # spvec_from_dense clamps nnz to out_cap — detect the overflow
             # before re-sparsifying or truncation would be silent
             ok = ok & (jnp.sum(piece != 0) <= out_cap)
@@ -185,10 +189,11 @@ def spmspv(a: DistSpMat, x: DistSpVec, sr: Semiring = ARITHMETIC, *,
         yi_s, yv_s = yi[order2], yv[order2]
         bi = bi.at[slot].set(yi_s, mode="drop")
         bv = bv.at[slot].set(yv_s, mode="drop")
-        bi = jax.lax.all_to_all(bi.reshape(pc, cap_d), "col", 0, 0) \
-            .reshape(pc * cap_d)
-        bv = jax.lax.all_to_all(bv.reshape(pc, cap_d), "col", 0, 0) \
-            .reshape(pc * cap_d)
+        with _obs.span("exchange"):
+            bi = jax.lax.all_to_all(bi.reshape(pc, cap_d), "col", 0, 0) \
+                .reshape(pc * cap_d)
+            bv = jax.lax.all_to_all(bv.reshape(pc, cap_d), "col", 0, 0) \
+                .reshape(pc * cap_d)
         # localize to my piece and merge duplicates from the pc sources
         j = jax.lax.axis_index("col")
         valid = bi != SENTINEL
@@ -226,7 +231,8 @@ def transpose_spvec_layout(v: DistSpVec, *, mesh: Mesh) -> DistSpVec:
 
     def body(xi, xv, xn):
         f = lambda t: jax.lax.ppermute(t, ("row", "col"), perm)
-        return f(xi), f(xv), f(xn)
+        with _obs.span("exchange"):
+            return f(xi), f(xv), f(xn)
 
     yi, yv, yn = shard_map(
         body, mesh=mesh,

@@ -141,6 +141,7 @@ def segment_reduce_pallas(values, seg_ids, num_segments: int, tag: str,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="segment_reduce_pallas",
     )(ids, v)
     out = out[:s]
     if tag != "sum":

@@ -1,31 +1,35 @@
-"""Host-side flight recorder: spans, counters, gauges, structured events.
+"""Host-side flight recorder: spans, counters, structured events.
 
 The observability substrate every perf PR proves its claims against
 (DESIGN.md §9). Three design rules, in priority order:
 
-1. **Near-zero overhead when disabled.** Every hook starts with one read
-   of the module-level ``_enabled`` boolean and returns immediately —
-   ``span()`` hands back a shared no-op singleton (no allocation beyond
-   the caller's kwargs), ``counter_add``/``event`` return before touching
-   any state. Attribute *formatting* never happens at record time; raw
-   values are stored and stringified only at export.
-2. **Host boundaries only.** Like ``robust/faults.py``, hooks are placed
-   in host-level code, never inside jit/shard_map-traced functions. As a
-   second line of defense, :func:`recording` (and therefore ``span``)
-   checks ``jax.core.trace_state_clean()`` once per call when enabled, so
-   a hook reached from inside a trace quietly no-ops instead of recording
-   a meaningless trace-time duration or crashing on a Tracer.
+1. **Near-zero overhead when disabled.** A host span with neither the
+   recorder nor a profiler session recording costs one trace-state read
+   and one ``TraceAnnotation.is_enabled()`` check, and ``span()`` hands
+   back a shared no-op singleton (no allocation beyond the caller's
+   kwargs); ``counter_add``/``event`` read the module-level ``_enabled``
+   boolean and return before touching any state. Attribute *formatting*
+   never happens at record time; raw values are stored and stringified
+   only at export.
+2. **One clock, one set of names.** A span reached on the host opens a
+   ``jax.profiler.TraceAnnotation`` of its name whenever a profiler
+   session records, so it lands on the profiler's host line beside JAX's
+   own events, and is kept in the in-memory buffer when the recorder is
+   on. A span reached while JAX traces a program (jit/shard_map/scan)
+   enters ``jax.named_scope`` of its name instead, so the program's
+   device ops carry the layer in their op path. The scope does not depend
+   on whether the recorder is on: turning it on changes no program.
 3. **Deterministic metrics.** Counter and event *values* derive only from
    data sizes and control-flow decisions (payload bytes, retry counts,
-   ladder rungs) — two identically-seeded runs produce identical counter
-   totals, which the subprocess determinism test pins.
+   ladder rungs, host syncs) — two identically-seeded runs produce
+   identical counter totals, which the subprocess determinism test pins.
 
 Enablement: ``REPRO_TRACE=<path>`` (Chrome-trace dump at exit, see
 ``export.py``) or ``REPRO_OBS=1`` (record + ``snapshot()`` only) at
 import, or :func:`enable` / the scoped :func:`capture` at runtime.
 
 This module imports nothing from ``repro`` (robust and core import us);
-jax is imported lazily and only while recording.
+jax is imported lazily, at the first span.
 """
 from __future__ import annotations
 
@@ -49,19 +53,37 @@ _events: list[tuple] = []
 # counters: monotonic totals + a (name, t, total) series for counter tracks
 _counters: dict[str, float] = {}
 _counter_series: list[tuple] = []
-_gauges: dict[str, float] = {}
 
 _MAX_RECORDS = 1_000_000         # backstop against unbounded growth
 
+# jax's trace context and eager trace, TraceAnnotation.is_enabled,
+# TraceAnnotation and named_scope: bound by _bind_jax at the first use
+_ctx = _eval_trace = _profiling = _TraceAnnotation = _named_scope = None
+
 
 # --------------------------------------------------------------------------
-# tracing guard (second line of defense behind host-boundary placement)
+# where a hook runs: host or jax tracing; is a profiler session recording
 # --------------------------------------------------------------------------
+
+def _bind_jax():
+    """Bind the jax hooks on first use (jax is imported lazily)."""
+    global _ctx, _eval_trace, _profiling, _TraceAnnotation, _named_scope
+    import jax
+    from jax._src import core, source_info_util
+    _ctx, _eval_trace = core.trace_ctx, core.eval_trace
+    # the hooks' frames are not where an op comes from
+    source_info_util.register_exclusion(__file__)
+    _profiling = jax.profiler.TraceAnnotation.is_enabled
+    _TraceAnnotation = jax.profiler.TraceAnnotation
+    _named_scope = jax.named_scope
+
 
 def tracing() -> bool:
-    """True when called from inside jax tracing (jit/shard_map/scan)."""
-    from jax._src.core import trace_state_clean
-    return not trace_state_clean()
+    """True when called from inside jax tracing (jit/shard_map/scan):
+    the thread's current trace is not jax's eager one."""
+    if _ctx is None:
+        _bind_jax()
+    return _ctx.trace is not _eval_trace
 
 
 def enabled() -> bool:
@@ -105,7 +127,7 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "t0", "depth")
+    __slots__ = ("name", "attrs", "t0", "depth", "annotation")
 
     def __init__(self, name: str, attrs):
         self.name = name
@@ -115,11 +137,17 @@ class _Span:
         stack = _stack()
         self.depth = len(stack)
         stack.append(self)
+        self.annotation = (_TraceAnnotation(self.name) if _profiling()
+                           else None)
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -134,29 +162,37 @@ class _Span:
 
 
 def span(name: str, **attrs):
-    """Context manager timing one host-side region.
+    """Context manager naming one region of the program.
 
-    ``with obs.span("spgemm2d.execute", schedule=s): ...`` — thread-safe,
-    nestable (depth comes from a thread-local stack), wall-time anchored
-    (the export maps the monotonic timestamps onto the wall-clock epoch).
-    Returns a shared no-op when disabled or when called from inside jax
-    tracing.
+    ``with obs.span("spgemm2d.execute", schedule=s): ...`` — on the host,
+    a profiler annotation while a profiler session records, and a
+    recorded span (thread-safe, nestable: depth comes from a thread-local
+    stack) while the recorder is on; the shared no-op when neither
+    records. Inside jax tracing, ``jax.named_scope(name)``: the ops traced
+    in the region carry ``name`` in their op path.
     """
-    if not _enabled:
-        return _NOOP
-    if tracing():
-        return _NOOP
-    return _Span(name, attrs)
+    if _ctx is None:
+        _bind_jax()
+    if _ctx.trace is not _eval_trace:
+        return _named_scope(name)
+    if _enabled:
+        return _Span(name, attrs)
+    if _profiling():
+        return _TraceAnnotation(name)
+    return _NOOP
 
 
 def timed(name: str, **attrs):
-    """Decorator form of :func:`span` for whole host-level functions."""
+    """Decorator form of :func:`span` for whole functions."""
     def deco(fn):
         import functools
+        if _ctx is None:
+            _bind_jax()
 
         @functools.wraps(fn)
         def wrapper(*a, **kw):
-            if not _enabled:
+            if not (_enabled or _profiling()) \
+                    and _ctx.trace is _eval_trace:
                 return fn(*a, **kw)
             with span(name, **attrs):
                 return fn(*a, **kw)
@@ -178,7 +214,7 @@ def sync(x):
 
 
 # --------------------------------------------------------------------------
-# metrics: counters / gauges / instant events
+# metrics: counters / instant events
 # --------------------------------------------------------------------------
 
 def counter_add(name: str, value: float = 1):
@@ -191,16 +227,6 @@ def counter_add(name: str, value: float = 1):
         _counters[name] = total
         if len(_counter_series) < _MAX_RECORDS:
             _counter_series.append((name, t, total))
-
-
-def gauge_set(name: str, value: float):
-    if not _enabled:
-        return
-    t = time.perf_counter() - _epoch_perf
-    with _lock:
-        _gauges[name] = value
-        if len(_counter_series) < _MAX_RECORDS:
-            _counter_series.append((name, t, value))
 
 
 def event(name: str, **attrs):
@@ -255,7 +281,6 @@ def reset():
         _events.clear()
         _counters.clear()
         _counter_series.clear()
-        _gauges.clear()
 
 
 @contextlib.contextmanager
@@ -271,12 +296,11 @@ def capture():
     global _enabled
     with _lock:
         saved = (_enabled, list(_spans), list(_events), dict(_counters),
-                 list(_counter_series), dict(_gauges))
+                 list(_counter_series))
         _spans.clear()
         _events.clear()
         _counters.clear()
         _counter_series.clear()
-        _gauges.clear()
     _enabled = False
     enable()
     try:
@@ -289,8 +313,6 @@ def capture():
             _counters.clear()
             _counters.update(saved[3])
             _counter_series[:] = saved[4]
-            _gauges.clear()
-            _gauges.update(saved[5])
 
 
 # --------------------------------------------------------------------------
@@ -305,10 +327,10 @@ def _percentile(sorted_vals: list[float], q: float) -> float:
 
 
 def snapshot() -> dict[str, Any]:
-    """Plain-dict summary: per-site span stats + counter/gauge totals.
+    """Plain-dict summary: per-site span stats + counter totals.
 
     ``{"spans": {site: {count, total_us, p50_us, p99_us}},
-       "counters": {...}, "gauges": {...}, "events": {name: count},
+       "counters": {...}, "events": {name: count},
        "deadline": {site: {n, median_s, budget_s, trips}}}``
 
     This is what ``benchmarks/run.py --json`` embeds as ``trace_summary``
@@ -319,7 +341,6 @@ def snapshot() -> dict[str, Any]:
         spans = list(_spans)
         evs = list(_events)
         cts = dict(_counters)
-        gs = dict(_gauges)
     per_site: dict[str, list[float]] = {}
     for name, _tid, _t0, dur, _depth, _attrs in spans:
         per_site.setdefault(name, []).append(dur * 1e6)
@@ -335,8 +356,7 @@ def snapshot() -> dict[str, Any]:
     ev_counts: dict[str, int] = {}
     for name, _tid, _t, _attrs in evs:
         ev_counts[name] = ev_counts.get(name, 0) + 1
-    out = {"spans": span_stats, "counters": cts, "gauges": gs,
-           "events": ev_counts}
+    out = {"spans": span_stats, "counters": cts, "events": ev_counts}
     dl = _deadline_stats()
     if dl:
         out["deadline"] = dl

@@ -113,7 +113,7 @@ def apply_rung(rung: str, plan):
         f"robust: degrading pipeline -> {rung!r} "
         f"(after {getattr(plan, 'attempts', '?')} attempts; "
         f"ladder so far: {getattr(plan, 'degraded', ())})",
-        RuntimeWarning, stacklevel=3)
+        RuntimeWarning, stacklevel=4)
     # mirror the warning into the flight recorder so a REPRO_TRACE capture
     # is self-contained — shed detail must not live only on stderr
     _obs.event("ladder.rung", rung=rung,

@@ -11,6 +11,8 @@ import sys
 import threading
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -366,3 +368,207 @@ class TestDeterminism:
             outs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
         assert outs[0] == outs[1]
         assert any(k.startswith("comm.bytes.") for k in outs[0])
+
+
+# --------------------------------------------------------------------------
+# one clock: spans as profiler annotations and named scopes
+# --------------------------------------------------------------------------
+
+def _sorted_twice(x):
+    from repro.core.coo import COO
+    with obs.span("layer.demo"):
+        y = jnp.sort(x) * 2
+    n = x.shape[0]
+    c = COO(jnp.arange(n, dtype=jnp.int32)[::-1],
+            jnp.zeros(n, jnp.int32), x, jnp.int32(n), (n, 1), "none")
+    return y, c.sort("row").val
+
+
+def _lowered(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+def _host_line(path, window):
+    """Events ``(start, end, name)`` of the host line that holds
+    ``window`` in the profiler trace at ``path``."""
+    from jax.profiler import ProfileData
+    pd = ProfileData.from_file(path)
+    for line in pd.find_plane_with_name("/host:CPU").lines:
+        evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+               for e in line.events]
+        if any(n == window for _, _, n in evs):
+            return evs
+    raise AssertionError(f"no host line holds {window}")
+
+
+def _profile(tmp_path, body):
+    import glob
+    from jax.profiler import TraceAnnotation
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("bench.window"):
+            body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    return _host_line(path, "bench.window")
+
+
+class TestProfilerClock:
+    def test_span_inside_jit_names_the_ops(self):
+        x = jnp.arange(8, dtype=jnp.float32)
+        text = _lowered(_sorted_twice, x)
+        assert "jit(_sorted_twice)/layer.demo/mul" in text
+        # the engine's own decorator names the ops it traces
+        assert "jit(_sorted_twice)/merge.sort/" in text
+
+    def test_lowered_program_is_the_same_with_the_recorder_on(self):
+        x = jnp.arange(8, dtype=jnp.float32)
+        off = _lowered(_sorted_twice, x)
+        with obs.capture() as rec:
+            on = _lowered(_sorted_twice, x)
+            assert rec.snapshot()["spans"] == {}   # nothing on the host
+        assert on == off
+
+    def test_disabled_span_inside_tracing_is_a_named_scope(self):
+        seen = []
+
+        @jax.jit
+        def f(x):
+            seen.append(obs.span("layer.demo"))
+            return x + 1
+
+        f(jnp.ones(2))
+        assert seen and seen[0] is not recorder._NOOP
+        assert obs.span("layer.demo") is recorder._NOOP
+
+    def test_host_span_is_an_annotation_on_the_same_host_line(self,
+                                                             tmp_path):
+        def body():
+            with obs.span("program.demo"):
+                jnp.ones(4).block_until_ready()
+            with obs.span("plan.demo", k=1):     # attributes stay off the
+                pass                            # annotation's name
+
+        evs = _profile(tmp_path, body)
+        (w0, w1, _), = [e for e in evs if e[2] == "bench.window"]
+        for name in ("program.demo", "plan.demo"):
+            (s, e, _), = [x for x in evs if x[2] == name]
+            assert w0 <= s <= e <= w1, name
+
+    def test_recorded_span_is_also_an_annotation(self, tmp_path):
+        with obs.capture() as rec:
+            evs = _profile(tmp_path, lambda: obs.span("fetch.demo")
+                           .__enter__().__exit__(None, None, None))
+            assert rec.snapshot()["spans"]["fetch.demo"]["count"] == 1
+        assert any(n == "fetch.demo" for _, _, n in evs)
+
+    def test_no_annotation_without_a_profiler_session(self):
+        from jax.profiler import TraceAnnotation
+        assert not TraceAnnotation.is_enabled()
+        assert obs.span("program.demo") is recorder._NOOP
+
+
+class TestPrograms:
+    def test_program_span_named_by_the_enclosing_function(self, mesh):
+        from jax.sharding import PartitionSpec as P
+        from repro.core import compat
+
+        def double(x):
+            def body(t):
+                return t * 2
+            return compat.shard_map(body, mesh=mesh, in_specs=P("row"),
+                                    out_specs=P("row"))
+
+        prog = double(None)
+        assert prog.name == "program.double"
+        x = jnp.arange(4.0)
+        # the jitted function's own attributes stay reachable
+        assert "mul" in prog.lower(x).as_text()
+        with obs.capture() as rec:
+            np.testing.assert_array_equal(np.asarray(prog(x)), 2 * x)
+            snap = rec.snapshot()
+        assert snap["spans"]["program.double"]["count"] == 1
+
+    def test_wrapper_frames_are_not_an_ops_source(self):
+        """A program's ops keep the caller's line as their source (the
+        profiler's ``source`` stat, which bench/ops.json reads)."""
+        from jax._src import source_info_util
+        from repro.core import compat
+        obs.span("bind")
+        for path in (compat.__file__, recorder.__file__):
+            assert not source_info_util.is_user_filename(path), path
+        assert source_info_util.is_user_filename(__file__)
+
+    def test_program_name_of_a_module_level_function(self):
+        from repro.core.compat import program_name
+        assert program_name(make_graph) == "program.make_graph"
+
+
+def _sync_counters(snap):
+    return {k: v for k, v in snap["counters"].items()
+            if k.startswith("host.sync.") or k == "fetch.bytes"}
+
+
+class TestHostSyncs:
+    def _planned_and_bfs(self, mesh):
+        from repro.apps import bfs_levels
+        _, (r, c, v) = make_graph(seed=5)
+        A = DistSpMat.from_global_coo((40, 40), r, c, v, (1, 1), mesh=mesh)
+        with obs.capture() as rec:
+            C, _ = spgemm_planned(A, A, ARITHMETIC, mesh=mesh)
+            C.to_global_coo()
+            levels = bfs_levels(A, 0, mesh=mesh)
+            snap = rec.snapshot()
+        return A, C, levels, snap
+
+    def test_counters_repeat_across_seeded_runs(self, mesh):
+        first = _sync_counters(self._planned_and_bfs(mesh)[3])
+        second = _sync_counters(self._planned_and_bfs(mesh)[3])
+        assert first == second
+        for key in ("host.sync.plan.nnz", "host.sync.plan.ok",
+                    "host.sync.plan.frontier_nnz", "host.sync.bfs.frontier",
+                    "host.sync.fetch", "fetch.bytes"):
+            assert first.get(key, 0) > 0, (key, first)
+
+    def test_fetch_bytes_from_shapes(self, mesh):
+        _, (r, c, v) = make_graph(seed=5)
+        A = DistSpMat.from_global_coo((40, 40), r, c, v, (1, 1), mesh=mesh)
+        with obs.capture() as rec:
+            A.to_global_coo()
+            cnt = rec.counters()
+            assert rec.snapshot()["spans"]["dist.fetch"]["count"] == 1
+        # row, col and val of every padded slot, and the nnz per tile
+        assert cnt["fetch.bytes"] == A.cap * (4 + 4 + 4) + 4
+        assert cnt["host.sync.fetch"] == 4
+
+    def test_one_frontier_read_a_level(self, mesh):
+        _, _, levels, snap = self._planned_and_bfs(mesh)
+        # one read a level, and the read that ends the search
+        assert snap["counters"]["host.sync.bfs.frontier"] \
+            == int(levels.max()) + 2
+        assert snap["spans"]["bfs.level"]["count"] == int(levels.max()) + 1
+
+    def test_no_read_for_the_recorder_when_it_is_off(self, mesh,
+                                                     monkeypatch):
+        from repro.apps import bfs as bfs_mod
+        _, (r, c, v) = make_graph(seed=5)
+        A = DistSpMat.from_global_coo((40, 40), r, c, v, (1, 1), mesh=mesh)
+        reads = []
+        real = bfs_mod.spvec_nnz
+        monkeypatch.setattr(bfs_mod, "spvec_nnz",
+                            lambda x: reads.append(1) or real(x))
+        levels = bfs_mod.bfs_levels(A, 0, mesh=mesh)
+        assert len(reads) == int(levels.max()) + 2
+
+
+def test_planner_warnings_point_at_the_caller(mesh):
+    """The planned call runs inside its ``plan.spgemm`` span's wrapper; its
+    warnings still name the line that called it."""
+    _, (r, c, v) = make_graph(seed=3)
+    A = DistSpMat.from_global_coo((40, 40), r, c, v, (1, 1), mesh=mesh)
+    with audit.at_level("boundary"), \
+            faults.inject("dist.compressed_exchange:corrupt_val:count=99"), \
+            pytest.warns(RuntimeWarning) as caught:
+        spgemm_planned(A, A, ARITHMETIC, mesh=mesh, compress="int8")
+    assert {w.filename for w in caught} == {__file__}
