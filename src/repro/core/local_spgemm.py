@@ -50,6 +50,24 @@ def spgemm_flops(a: COO, b: COO) -> Array:
     return jnp.sum(jnp.where(b.mask(), end - start, 0))
 
 
+def _slot_owner(off: Array, prod_cap: int) -> Array:
+    """Operand entry that owns each of ``prod_cap`` product slots.
+
+    Entry ``t`` owns slots ``off[t] .. off[t+1] - 1``, ``off`` being the
+    exclusive prefix sum of the entries' product counts. Each entry marks
+    its first slot with its id; where entries share an offset, all but the
+    last own no slot and the max keeps the last. A running max then carries
+    each id over its entry's slots: O(prod_cap) work, where a binary search
+    per slot is O(prod_cap · log cap). ``off`` is nondecreasing, so the
+    scatter needs no sort; offsets past ``prod_cap`` are dropped. Slots at
+    or past the product count get the last owner; callers mask them.
+    """
+    ids = jnp.arange(off.shape[0], dtype=jnp.int32)
+    marks = jnp.zeros(prod_cap, jnp.int32).at[off].max(
+        ids, mode="drop", indices_are_sorted=True)
+    return jax.lax.cummax(marks)
+
+
 @_obs.timed("expand")
 def _expand(a: COO, b: COO, sr: Semiring, prod_cap: int):
     """ESC expansion: one slot per scalar multiply (O(flops) work).
@@ -77,9 +95,7 @@ def _expand(a: COO, b: COO, sr: Semiring, prod_cap: int):
     ok = nprod <= prod_cap
 
     s = jnp.arange(prod_cap, dtype=jnp.int32)
-    # which B-nonzero does product slot s belong to?
-    t = jnp.searchsorted(off + cnt, s, side="right").astype(jnp.int32)
-    tc = jnp.clip(t, 0, sb.cap - 1)
+    tc = _slot_owner(off, prod_cap)              # B-nonzero of slot s
     a_idx = jnp.clip(start[tc] + (s - off[tc]), 0, sa.cap - 1)
     valid = s < nprod
 
@@ -104,9 +120,7 @@ def _expand_sorted_b(a: COO, b: COO, sr: Semiring, prod_cap: int):
     ok = nprod <= prod_cap
 
     s = jnp.arange(prod_cap, dtype=jnp.int32)
-    # which A-nonzero does product slot s belong to?
-    t = jnp.searchsorted(off + cnt, s, side="right").astype(jnp.int32)
-    tc = jnp.clip(t, 0, a.cap - 1)
+    tc = _slot_owner(off, prod_cap)              # A-nonzero of slot s
     b_idx = jnp.clip(start[tc] + (s - off[tc]), 0, b.cap - 1)
     valid = s < nprod
 

@@ -10,8 +10,9 @@ except ImportError:          # degrade: property tests importorskip at run
     from _hypothesis_stub import given, settings, st
 
 from repro.core import semiring as S
-from repro.core.coo import COO, SENTINEL, ewise_intersect, ewise_union
-from repro.core.local_spgemm import (compression_ratio, spgemm_auto,
+from repro.core.coo import (COO, SENTINEL, column_range, ewise_intersect,
+                            ewise_union, row_range)
+from repro.core.local_spgemm import (_expand, compression_ratio, spgemm_auto,
                                      spgemm_dense, spgemm_esc, spgemm_flops)
 from repro.core.spmv_local import (spmspv_bucket, spmspv_sort, spmspv_spa,
                                    spmspv_auto, spmv_col, spmv_row,
@@ -188,6 +189,90 @@ class TestLocalSpGEMM:
         assert bool(ok1) and bool(ok2)
         np.testing.assert_allclose(np.asarray(c1.to_dense()),
                                    np.asarray(c2.to_dense()), rtol=1e-5)
+
+
+def _expand_by_search(a, b, sr, prod_cap):
+    """The expansion with each slot's owner found by a binary search over
+    the owners' end offsets (``searchsorted``): the reference for the
+    scatter-and-running-max owner map of ``_expand``."""
+    sorted_b = b.order == "row" and a.order != "col"
+    if sorted_b:
+        walk, other = a, b                  # walk A, ranges of row-sorted B
+        start, end = row_range(b.row, jnp.where(a.mask(), a.col, SENTINEL))
+    else:
+        other, walk = a.sort("col"), b      # walk B, ranges of col-sorted A
+        start, end = column_range(other.col,
+                                  jnp.where(b.mask(), b.row, SENTINEL))
+    cnt = jnp.where(walk.mask(), end - start, 0)
+    off = jnp.cumsum(cnt) - cnt
+    nprod = jnp.sum(cnt)
+    s = jnp.arange(prod_cap, dtype=jnp.int32)
+    t = jnp.searchsorted(off + cnt, s, side="right").astype(jnp.int32)
+    tc = jnp.clip(t, 0, walk.cap - 1)
+    o_idx = jnp.clip(start[tc] + (s - off[tc]), 0, other.cap - 1)
+    valid = s < nprod
+    ai, bi = (tc, o_idx) if sorted_b else (o_idx, tc)
+    sa, sb = (walk, other) if sorted_b else (other, walk)
+    out_dtype = sr.out_dtype(a.dtype, b.dtype)
+    rows = jnp.where(valid, sa.row[ai], SENTINEL)
+    cols = jnp.where(valid, sb.col[bi], SENTINEL)
+    vals = sr.mul(sa.val[ai], sb.val[bi]).astype(out_dtype)
+    vals = jnp.where(valid.reshape((-1,) + (1,) * (vals.ndim - 1)), vals,
+                     jnp.asarray(sr.add.identity, out_dtype))
+    return rows, cols, vals, nprod, nprod <= prod_cap
+
+
+def _tile(rng, m, n, nnz, cap, vdims=()):
+    """A row-sorted tile of ``nnz`` distinct random entries."""
+    flat = np.sort(rng.choice(m * n, size=nnz, replace=False))
+    val = rng.random((nnz,) + vdims).astype(np.float32) + 0.5
+    return COO.from_entries((m, n), flat // n, flat % n, val, cap=cap,
+                            order="row")
+
+
+def _expand_case(name, rng):
+    """(A, B, prod_cap) for one named case of the owner-map equivalence."""
+    if name == "random":
+        return _tile(rng, 40, 30, 150, 160), _tile(rng, 30, 50, 200, 210), 4096
+    if name == "zero_counts":          # most of A's columns hit empty B rows
+        b = _tile(rng, 60, 40, 30, 40)
+        return _tile(rng, 50, 60, 300, 320), b, 2048
+    if name == "empty":
+        return (COO.empty((20, 24), 32), _tile(rng, 24, 18, 40, 48), 256)
+    if name == "one_entry":
+        return _tile(rng, 1, 1, 1, 1), _tile(rng, 1, 7, 5, 8), 16
+    if name == "overflow":             # nprod > prod_cap: ok is false
+        return _tile(rng, 40, 30, 300, 320), _tile(rng, 30, 50, 500, 512), 777
+    if name == "vector":
+        return (_tile(rng, 30, 20, 90, 96, (3,)),
+                _tile(rng, 20, 25, 80, 96, (3,)), 1024)
+    raise ValueError(name)
+
+
+EXPAND_CASES = ["random", "zero_counts", "empty", "one_entry", "overflow",
+                "vector"]
+
+
+class TestExpandOwnerMap:
+    @pytest.mark.parametrize("sr", [S.ARITHMETIC, S.MIN_PLUS],
+                             ids=lambda s: s.name)
+    @pytest.mark.parametrize("path", ["sorted_b", "col_sorted_a"])
+    @pytest.mark.parametrize("case", EXPAND_CASES)
+    def test_bit_identical_to_search(self, case, path, sr):
+        rng = np.random.default_rng(EXPAND_CASES.index(case))
+        a, b, prod_cap = _expand_case(case, rng)
+        if path == "col_sorted_a":
+            a = a.sort("col")
+        assert (b.order == "row" and a.order != "col") == (path == "sorted_b")
+        got = _expand(a, b, sr, prod_cap)
+        ref = _expand_by_search(a, b, sr, prod_cap)
+        for g, r in zip(got, ref):
+            g, r = np.atleast_1d(g), np.atleast_1d(r)
+            assert g.dtype == r.dtype and g.shape == r.shape
+            np.testing.assert_array_equal(g.view(np.uint8), r.view(np.uint8))
+        nprod, ok = int(got[3]), bool(got[4])
+        assert ok == (case != "overflow") and ok == (nprod <= prod_cap)
+        assert (nprod == 0) == (case == "empty")
 
 
 class TestSpMV:

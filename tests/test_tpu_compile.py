@@ -10,18 +10,27 @@ compiled at the size chip_smoke.py reaches: a 2^20-entry stream into
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU compiler's library.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.coo import COO
+from repro.core.local_spgemm import _expand
+from repro.core.semiring import ARITHMETIC
 from repro.kernels.segreduce import segment_reduce_pallas
 from repro.kernels.semiring_matmul import semiring_matmul
 
 STREAM = 1 << 20
 SEGMENTS = 32768
+# the local expand at the benchmark's largest product buffer
+# (tc.urand15's 2^25 slots): a 2^20-entry A against a 2^16-entry B
+EXPAND_SLOTS = 1 << 25
+EXPAND_A, EXPAND_B = 1 << 20, 1 << 16
 
 # (tag, value dtype, identity): PLUS on f32, MIN_INT on int32, and
 # BOOLEAN's LOR, which the engine tags "max" on bool values
@@ -80,3 +89,38 @@ def test_semiring_matmul_compiles(one_chip):
         lambda x, y: semiring_matmul(x, y, kind="plus_times",
                                      interpret=False), a, a)
     assert compiled.out_info.shape == (512, 512)
+
+
+def _while_shapes(hlo: str):
+    """Element counts of the arrays each ``while`` op of an HLO text
+    carries, one list per op."""
+    out = []
+    for line in hlo.splitlines():
+        if " while(" not in line:
+            continue
+        dims = re.findall(r"\b[a-z]+\d*\[([\d,]*)\]", line.split(" while(")[0])
+        out.append([math.prod(int(x) for x in d.split(",") if x)
+                    for d in dims])
+    return out
+
+
+def test_expand_has_no_loop_over_slots(one_chip):
+    """The slot -> owner map of the local expand is a scatter and a running
+    max, not a loop over the product slots: no ``while`` op of the
+    compiled expand carries a 2^25-slot array (the operand-side range
+    searches over 2^20 queries may loop)."""
+    def coo(n):
+        return (jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+
+    def fn(a, b):
+        a = COO(*a, shape=(32768, 32768), order="none")
+        b = COO(*b, shape=(32768, 32768), order="row")
+        return _expand(a, b, ARITHMETIC, EXPAND_SLOTS)
+
+    compiled = jax.jit(fn).lower(coo(EXPAND_A), coo(EXPAND_B)).compile()
+    assert compiled.out_info[0].shape == (EXPAND_SLOTS,)
+    loops = _while_shapes(compiled.as_text())
+    assert not any(EXPAND_SLOTS in sizes for sizes in loops), loops
